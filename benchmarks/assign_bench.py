@@ -41,8 +41,8 @@ from repro.kernels.candidate_assign import (candidate_assign_tiled,
 from repro.kernels.center_knn import center_knn
 from repro.kernels.ops import (assign_nearest_pallas, candidate_assign_rowwise,
                                group_by_cluster_device, k2_assign_grouped,
-                               rowwise_grid_steps, scatter_from_grouped,
-                               tiled_grid_steps)
+                               resolve_interpret, rowwise_grid_steps,
+                               scatter_from_grouped, tiled_grid_steps)
 
 CONFIGS = [
     # (n, k, kn, d, bn, bkn)
@@ -161,7 +161,7 @@ def bench_config(n, k, kn, d, bn, bkn, repeats, interpret):
 
 
 def run(fast: bool = False, repeats: int = 3, out: str = "BENCH_assign.json"):
-    interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret()
     results = []
     for cfg in (FAST_CONFIGS if fast else CONFIGS):
         r = bench_config(*cfg, repeats=repeats, interpret=interpret)

@@ -7,6 +7,7 @@ stand-ins of the paper's datasets (see repro.data.synthetic).
 """
 from __future__ import annotations
 
+import os
 import time
 
 import jax
@@ -14,6 +15,24 @@ import jax.numpy as jnp
 
 from repro.core import OpCounter, fit
 from repro.data import dataset_like
+
+_REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def use_compile_cache() -> str:
+    """Place JAX's persistent compilation cache before the first compile.
+
+    ``JAX_COMPILATION_CACHE_DIR``, when set, already names the cache (JAX
+    reads it) and is left alone. Otherwise the cache goes to the fixed
+    directory ``<repo>/.jax_cache``: the path is part of what later runs
+    look up, so it never moves. Returns the directory in use. Entry
+    points call this; importing the library never does."""
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = os.path.join(_REPO, ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
 
 # reduced-scale grid for the CPU-only CI budget
 BENCH_DATASETS = ("mnist50", "usps", "tinygist10k", "covtype")

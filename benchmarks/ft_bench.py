@@ -156,6 +156,9 @@ def run(fast: bool = False, out: str | None = None, shape=None):
     if out is None:     # keep CI-mode runs from clobbering the acceptance
         out = "BENCH_ft.fast.json" if fast else "BENCH_ft.json"
     env = dict(os.environ)
+    # a CPU rehearsal mesh: the child must never claim an accelerator
+    # that this parent process may hold
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
     env[_CHILD] = json.dumps({"fast": fast, "out": out, "shape": shape})
     env.setdefault("PYTHONPATH", "src")

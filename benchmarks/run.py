@@ -249,6 +249,8 @@ def main() -> None:
                     help="machine-readable per-section report path")
     args, _ = ap.parse_known_args()
 
+    from .common import use_compile_cache
+    print(f"# compilation cache: {use_compile_cache()}")
     outdir = None
     perf_out = args.perf_out
     if args.smoke:

@@ -63,6 +63,7 @@ if __name__ == "__main__":
         child()
     else:
         env = dict(os.environ)
+        env["JAX_PLATFORMS"] = "cpu"   # a CPU mesh; never claim the chip
         env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         env[_CHILD] = "1"
         env.setdefault("PYTHONPATH", "src")

@@ -44,8 +44,9 @@ from .registry import EntryPoint, audit_entries
 
 HOST_PRIM_EXACT = frozenset({"infeed", "outfeed", "debug_print",
                              "outside_call"})
-COLLECTIVES = frozenset({"psum", "psum2", "pmax", "pmin", "pmean",
-                         "all_gather", "all_to_all", "ppermute", "pgather",
+COLLECTIVES = frozenset({"psum", "psum2", "psum_invariant", "pmax", "pmin",
+                         "pmean", "all_gather", "all_gather_invariant",
+                         "all_to_all", "ppermute", "pgather",
                          "reduce_scatter", "psum_scatter", "pbroadcast"})
 LOOP_PRIMS = frozenset({"scan", "while"})
 REGION_PRIMS = frozenset({"scan", "while", "cond"})
@@ -59,7 +60,7 @@ def _subjaxprs(params):
     """Every Jaxpr/ClosedJaxpr reachable from an eqn's params (pjit's
     ``jaxpr``, scan's ``jaxpr``, while's ``cond_jaxpr``/``body_jaxpr``,
     cond's ``branches``, pallas_call's kernel jaxpr, ...)."""
-    import jax.core as core
+    import jax.extend.core as core
     stack = list(params.values())
     while stack:
         v = stack.pop()

@@ -156,10 +156,10 @@ CHARGING_MAP: dict[str, str] = {
     "src/repro/core/model.py::_predict_batch":
         "KMeansModel.predict: add_distances/add_int8_ops/add_scan_bytes "
         "from the returned n_counted",
-    "src/repro/core/model.py::_build_router":
+    "src/repro/core/model.py::_router_groups":
         "KMeansModel.partial_fit refresh: add_distances((iters+1)·g·k); "
-        "the one-time from_result build is model setup outside the §2 "
-        "per-query/per-iteration tables",
+        "the one-time from_result build (and its cap sizing) is model "
+        "setup outside the §2 per-query/per-iteration tables",
     "src/repro/core/model.py::_graph_with_dists":
         "KMeansModel.partial_fit refresh: add_distances(k²); fit-side "
         "graph maintenance charged by charge_iteration's k·k term",

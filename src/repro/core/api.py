@@ -25,29 +25,28 @@ INITS = ("random", "kmeanspp", "gdi", "gdi_host", "gdi_device",
 
 
 def initialize(x: jax.Array, k: int, init: str, key: jax.Array,
-               counter: OpCounter, backend: str | None = None):
-    """Returns (centers, assignment_or_None).
+               counter: OpCounter, backend: str | None = None) -> jax.Array:
+    """Initial centers, (k, d).
 
     ``init="gdi"`` resolves to the frontier-batched device GDI when the
     fit runs on the Pallas fast path (``backend="pallas"``) so the whole
     program — init through convergence — stays on device, and to the
     host-loop reference otherwise. ``"gdi_host"`` / ``"gdi_device"`` pin
-    one explicitly.
+    one explicitly. The divisive inits' leaf assignments are dropped:
+    k²-means starts from the exact assignment (:func:`fit_k2means`).
     """
     if init == "random":
-        return random_init(x, k, key, counter), None
+        return random_init(x, k, key, counter)
     if init == "kmeanspp":
-        return kmeanspp_init(x, k, key, counter), None
+        return kmeanspp_init(x, k, key, counter)
     if init == "gdi":
-        if backend == "pallas":
-            return gdi_device_init(x, k, key, counter=counter)
-        return gdi_init(x, k, key, counter=counter)
+        init = "gdi_device" if backend == "pallas" else "gdi_host"
     if init == "gdi_host":
-        return gdi_init(x, k, key, counter=counter)
+        return gdi_init(x, k, key, counter=counter)[0]
     if init == "gdi_device":
-        return gdi_device_init(x, k, key, counter=counter)
+        return gdi_device_init(x, k, key, counter=counter)[0]
     if init == "gdi_parallel":
-        return gdi_parallel_init(x, k, key, counter=counter)
+        return gdi_parallel_init(x, k, key, counter=counter)[0]
     raise ValueError(f"unknown init {init!r}; expected one of {INITS}")
 
 
@@ -151,8 +150,8 @@ def fit(x: jax.Array, k: int, *, method: str = "k2means", init: str = "gdi",
                                             max_iters=max_iters, init=init,
                                             counter=counter, **kw))
 
-    centers, assignment = initialize(x, k, init, k_init, counter,
-                                     backend=kw.get("backend"))
+    centers = initialize(x, k, init, k_init, counter,
+                         backend=kw.get("backend"))
 
     if method == "lloyd":
         return done(fit_lloyd(x, centers, max_iters=max_iters,
@@ -161,8 +160,8 @@ def fit(x: jax.Array, k: int, *, method: str = "k2means", init: str = "gdi",
         return done(fit_elkan(x, centers, max_iters=max_iters,
                               counter=counter, **kw))
     if method == "k2means":
-        if assignment is None:
-            assignment = assign_nearest(x, centers, counter)
+        # the exact start fit_k2means requires (its docstring)
+        assignment = assign_nearest(x, centers, counter)
         return done(fit_k2means(x, centers, assignment, kn=kn,
                                 max_iters=max_iters, counter=counter, **kw))
     if method == "minibatch":

@@ -47,10 +47,11 @@ import time
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding
 
-from ..compat import shard_map
-from ..launch.mesh import dp_axes
+from ..kernels.ops import resolve_interpret
+from ..launch.mesh import auto_axes, dp_axes
 from ..launch.sharding import clustering_specs
 from .distance import (chunked_argmin_sqdist, chunked_candidate_argmin,
                        pairwise_sqdist, sqnorm)
@@ -200,7 +201,7 @@ def make_distributed_gdi_seed(mesh, k: int, *, data_axes=None,
     # the same major-to-minor order as the flat shard index above
     return shard_map(seed, mesh=mesh, in_specs=(xspec, rep),
                      out_specs=(rowspec, xspec, rowspec),
-                     check_rep=False)
+                     check_vma=False)
 
 
 @functools.partial(jax.jit, static_argnames=("k", "iters"))
@@ -337,12 +338,12 @@ def fit_distributed_k2means(x_global, k: int, kn: int, mesh, key, *,
     x_global = jnp.asarray(x_global)
     n, d = x_global.shape
     kn = min(kn, k)
+    mesh = auto_axes(mesh)
     data_axes = _axes(mesh, data_axes)
     nsh = _nshards(mesh, data_axes)
     pad = (-n) % nsh
     n_pad = n + pad
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret)
     if residency is None:
         residency = "resident" if backend == "pallas" else "rebuild"
     resident = backend != "legacy" and residency == "resident"
@@ -599,5 +600,11 @@ def fit_distributed_k2means(x_global, k: int, kn: int, mesh, key, *,
     else:
         energy = float(jnp.sum(w_e * sqnorm(x_e - c_fin[a_final])))
     assignment = jnp.asarray(jax.device_get(a_final)[:n])
+    placement = None
+    if state is not None:
+        names = ("xg", "pid", "ug", "lo_g") if resident else ("u", "lo")
+        placement = {nm: {sh.device.id: sh.data.shape[0]
+                          for sh in getattr(state, nm).addressable_shards}
+                     for nm in names}
     return KMeansResult(c_fin, assignment, energy, mon.it_done,
-                        counter.total, mon.history)
+                        counter.total, mon.history, placement=placement)

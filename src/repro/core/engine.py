@@ -56,8 +56,8 @@ import typing
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 
-from ..compat import shard_map
 from ..launch.mesh import dp_axes
 from ..launch.sharding import clustering_specs
 from .distance import chunked_candidate_top2, pairwise_sqdist, sqnorm
@@ -681,11 +681,6 @@ class K2Step:
                              "engines would re-quantize the whole layout "
                              "every iteration")
 
-    def _interpret(self) -> bool:
-        if self.interpret is not None:
-            return self.interpret
-        return jax.default_backend() != "tpu"
-
     def _n_local(self, n: int) -> int:
         nsh = self.shards()
         if n % nsh:
@@ -719,7 +714,8 @@ class K2Step:
     def build(self, n: int, d: int | None = None):
         self._validate()
         kn = min(self.kn, self.k)
-        interpret = self._interpret()
+        from ..kernels.ops import resolve_interpret
+        interpret = resolve_interpret(self.interpret)
         bn = self._bn(n, d)
 
         if self.residency == "resident":
@@ -742,7 +738,7 @@ class K2Step:
                 in_specs=(xspec, rowspec, state_specs),
                 out_specs=(state_specs,
                            StepStats(rep, rep, rep, rep, rep, rep)),
-                check_rep=False)
+                check_vma=False)
             return jax.jit(sharded)
 
         if self.mesh is None:
@@ -757,7 +753,7 @@ class K2Step:
         body = functools.partial(
             k2_iteration, kn=kn, backend=self.backend, chunk=self.chunk,
             bn=bn, bkn=self.bkn, interpret=interpret, psum_axes=axes)
-        # check_rep=False: pallas_call has no replication rule; the
+        # check_vma=False: pallas_call has no replication rule; the
         # replicated outputs (centers, neighbor lists, stats) are psum'd
         # or shard-identical by construction.
         sharded = shard_map(body, mesh=self.mesh,
@@ -765,7 +761,7 @@ class K2Step:
                             out_specs=(state_specs,
                                        StepStats(rep, rep, rep, rep, rep,
                                                  rep)),
-                            check_rep=False)
+                            check_vma=False)
         return jax.jit(sharded)
 
     def init_resident(self, x: jax.Array, w: jax.Array, centers: jax.Array,
@@ -786,7 +782,7 @@ class K2Step:
         sharded = shard_map(body, mesh=self.mesh,
                            in_specs=(xspec, rowspec, rep, rowspec),
                            out_specs=self._resident_specs(),
-                           check_rep=False)
+                           check_vma=False)
         return jax.jit(sharded)(x, w, centers,
                                 assignment.astype(jnp.int32))
 
@@ -799,7 +795,7 @@ class K2Step:
         _, rowspec, _ = clustering_specs(self.mesh, self.axes())
         sharded = shard_map(body, mesh=self.mesh,
                            in_specs=(self._resident_specs(),),
-                           out_specs=rowspec, check_rep=False)
+                           out_specs=rowspec, check_vma=False)
         return jax.jit(sharded)(state)
 
 

@@ -37,7 +37,8 @@ import jax
 import jax.numpy as jnp
 
 from ..kernels.ops import (choose_group_bn, group_by_cluster_device,
-                           grouped_capacity, segmented_scan)
+                           grouped_capacity, resolve_interpret,
+                           segmented_scan)
 from .opcount import OpCounter
 
 _INF = jnp.inf
@@ -276,8 +277,7 @@ def segmented_split_sweep(x: jax.Array, a: jax.Array, c_a: jax.Array,
     c_b' (k, d), phi_a (k,), phi_b (k,)). interpret=None auto-selects
     interpret mode off-TPU.
     """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret)
     n = x.shape[0]
     x_sq = jnp.sum(x * x, -1)
     tot_s = jax.ops.segment_sum(x, a, num_segments=k)
@@ -311,8 +311,7 @@ def gdi_round_step(x, a, centers, energies, sizes, nleaf, key, *, k: int,
     the updated state tuple. interpret=None auto-selects interpret mode
     off-TPU.
     """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret)
     n, d = x.shape
     slot = jnp.arange(k, dtype=jnp.int32)
     eligible = (slot < nleaf) & (sizes >= 2)
@@ -396,14 +395,11 @@ def _device_state(x, k: int):
 
 
 def _auto_impl(impl: str | None, interpret: bool | None):
-    on_tpu = jax.default_backend() == "tpu"
     if impl is None:
-        impl = "pallas" if on_tpu else "xla"
+        impl = "xla" if resolve_interpret() else "pallas"
     if impl not in ("pallas", "xla"):
         raise ValueError(f"unknown impl {impl!r}; expected 'pallas' or 'xla'")
-    if interpret is None:
-        interpret = not on_tpu
-    return impl, interpret
+    return impl, resolve_interpret(interpret)
 
 
 def _charge_round(counter: OpCounter, r: int, n: int, d: int,

@@ -29,6 +29,10 @@ class KMeansResult:
     # counted-op + memory-traffic breakdown (OpCounter.profile()), attached
     # by ``api.fit(..., profile=True)``; None otherwise.
     profile: dict | None = None
+    # {state array: {device id: rows}} of a mesh fit's row-sharded bound
+    # state (and resident arena), as placed for its last iteration; None
+    # on a single device.
+    placement: dict | None = None
 
 
 def update_centers(x: jax.Array, a: jax.Array, c_prev: jax.Array) -> jax.Array:

@@ -185,7 +185,7 @@ def make_guard(sb, n: int):
         resident_violations if resident else k2_violations, n=n_loc)
     if sb.mesh is None:
         return jax.jit(local)
-    from ..compat import shard_map
+    from jax import shard_map
     from ..launch.sharding import clustering_specs
     axes = sb.axes()
     _, rowspec, rep = clustering_specs(sb.mesh, axes)
@@ -199,7 +199,7 @@ def make_guard(sb, n: int):
     specs = sb._resident_specs() if resident else \
         K2State(rep, rowspec, rowspec, rowspec, rep, rep)
     return jax.jit(shard_map(body, mesh=sb.mesh, in_specs=(specs,),
-                             out_specs=rep, check_rep=False))
+                             out_specs=rep, check_vma=False))
 
 
 # ---------------------------------------------------------------------------

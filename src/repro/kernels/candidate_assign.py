@@ -80,50 +80,81 @@ def candidate_tables(c: jax.Array, cidx: jax.Array):
     return ctab, csqtab.astype(jnp.float32)
 
 
+def lane_sqnorms(x: jax.Array) -> jax.Array:
+    """(rows, d) tile -> (1, rows) squared row norms laid out along lanes
+    (exact f32 on the VPU: one transpose, one sublane reduction)."""
+    xt = x.astype(jnp.float32).T
+    return jnp.sum(xt * xt, axis=0, keepdims=True)
+
+
+def first_min_rows(dist: jax.Array):
+    """Column-wise best and second best of a (rows, cols) tile: returns
+    (d1 (1, cols), loc (1, cols) int32 first-min row, d2 (1, cols))."""
+    row = jax.lax.broadcasted_iota(jnp.int32, dist.shape, 0)
+    d1 = jnp.min(dist, axis=0, keepdims=True)
+    loc = jnp.min(jnp.where(dist == d1, row, dist.shape[0]), axis=0,
+                  keepdims=True)
+    d2 = jnp.min(jnp.where(row == loc, jnp.inf, dist), axis=0,
+                 keepdims=True)
+    return d1, loc, d2
+
+
+def block_rows(v: jax.Array, bn: int) -> jax.Array:
+    """(n,) per-row vector -> (n // bn, 1, bn): one lane-dense row per
+    point block, the layout every kernel here reads and writes per-row
+    scalars in (a bitcast of the flat vector at bn = 128)."""
+    return v.reshape(-1, 1, bn)
+
+
+def _row_spec(bn: int) -> pl.BlockSpec:
+    return pl.BlockSpec((1, 1, bn), lambda i, j, rs, sk: (i, 0, 0))
+
+
+def _cand_row_spec(knp: int) -> pl.BlockSpec:
+    # a table row's per-candidate scalars, fetched once per point block
+    return pl.BlockSpec((1, 1, knp),
+                        lambda i, j, rs, sk: (rs[i] * (1 - sk[i]), 0, 0))
+
+
+def _tile_rows(j, bkn: int):
+    return pl.ds(pl.multiple_of(j * bkn, bkn), bkn)
+
+
 def _tiled_kernel(rowsel_ref, skip_ref,              # scalar prefetch (SMEM)
-                  x_ref, ctab_ref, csq_ref, cidx_ref,
-                  prev_a_ref, prev_d1_ref, prev_d2_ref,
-                  a_ref, d1_ref, d2_ref,
-                  best_d1, best_d2, best_a, xsq):
+                  x_ref, ctab_ref, csq_ref,
+                  pos_ref, d1_ref, d2_ref,
+                  xsq, csq_col):
+    # Transposed tile: candidates on sublanes, the block's points on lanes,
+    # so every per-point result is a lane-dense (1, bn) row.
     i, j = pl.program_id(0), pl.program_id(1)
-    nt = pl.num_programs(1)
-    skipped = skip_ref[i] != 0
+    bkn = ctab_ref.shape[1]
 
     @pl.when(j == 0)
     def _init():
-        best_d1[...] = jnp.full_like(best_d1, jnp.inf)
-        best_d2[...] = jnp.full_like(best_d2, jnp.inf)
-        best_a[...] = jnp.zeros_like(best_a)
-        xsq[...] = jnp.sum(x_ref[...] * x_ref[...], axis=-1)
+        pos_ref[0] = jnp.zeros_like(pos_ref[0])
+        d1_ref[0] = jnp.full_like(d1_ref[0], jnp.inf)
+        d2_ref[0] = jnp.full_like(d2_ref[0], jnp.inf)
+        xsq[...] = lane_sqnorms(x_ref[...])
+        csq_col[...] = csq_ref[0].T                  # (kn_pad, 1)
 
-    @pl.when(jnp.logical_not(skipped))
+    @pl.when(skip_ref[i] == 0)
     def _compute():
-        x = x_ref[...]                               # (bn, d)
         ct = ctab_ref[0]                             # (bkn, d) candidate slab
-        cross = jax.lax.dot_general(x, ct, (((1,), (1,)), ((), ())),
+        # HIGHEST: these distances decide the exact assignment; a TPU's
+        # default f32 matmul is one bf16 pass
+        cross = jax.lax.dot_general(ct, x_ref[...], (((1,), (1,)), ((), ())),
+                                    precision=jax.lax.Precision.HIGHEST,
                                     preferred_element_type=jnp.float32)
-        dist = jnp.maximum(
-            xsq[...][:, None] - 2.0 * cross + csq_ref[0][None, :], 0.0)
-        cidx = cidx_ref[0]                           # (bkn,) int32
-        loc = jnp.argmin(dist, axis=1)               # first-min tie-break
-        hit = jax.lax.broadcasted_iota(jnp.int32, dist.shape, 1) \
-            == loc[:, None]
-        d1 = jnp.min(dist, axis=1)
-        d2 = jnp.min(jnp.where(hit, jnp.inf, dist), axis=1)
-        a_t = jnp.sum(jnp.where(hit, cidx[None, :], 0), axis=1)
-        # merge (d1, d2, a_t) into the running (best_d1, best_d2, best_a);
-        # strict < keeps the earlier tile on ties, matching a flat argmin.
-        better = d1 < best_d1[...]
-        best_d2[...] = jnp.minimum(jnp.maximum(best_d1[...], d1),
-                                   jnp.minimum(best_d2[...], d2))
-        best_a[...] = jnp.where(better, a_t, best_a[...])
-        best_d1[...] = jnp.minimum(best_d1[...], d1)
-
-    @pl.when(j == nt - 1)
-    def _flush():
-        a_ref[...] = jnp.where(skipped, prev_a_ref[...], best_a[...])
-        d1_ref[...] = jnp.where(skipped, prev_d1_ref[...], best_d1[...])
-        d2_ref[...] = jnp.where(skipped, prev_d2_ref[...], best_d2[...])
+        dist = jnp.maximum(xsq[...] - 2.0 * cross
+                           + csq_col[_tile_rows(j, bkn), :], 0.0)
+        d1, loc, d2 = first_min_rows(dist)
+        # merge into the running best; strict < keeps the earlier tile on
+        # ties, matching a flat argmin
+        best1 = d1_ref[0]
+        d2_ref[0] = jnp.minimum(jnp.maximum(best1, d1),
+                                jnp.minimum(d2_ref[0], d2))
+        pos_ref[0] = jnp.where(d1 < best1, loc + j * bkn, pos_ref[0])
+        d1_ref[0] = jnp.minimum(best1, d1)
 
 
 @functools.partial(jax.jit, static_argnames=("bn", "bkn", "interpret"))
@@ -143,6 +174,11 @@ def candidate_assign_tiled(x: jax.Array, ctab: jax.Array, csqtab: jax.Array,
     prev_a/prev_d1/prev_d2: fallbacks for skipped blocks, (n,).
     Returns (assignment int32 (n,), best sqdist f32 (n,),
              second-best sqdist f32 (n,)).
+
+    The kernel emits the winning candidate *column*; the column -> center
+    id lookup through ``cidx`` and the skipped-block fallbacks are one
+    fused elementwise pass here, so the kernel streams no per-candidate
+    ids and no fallback rows.
     """
     n, d = x.shape
     assert n % bn == 0
@@ -151,46 +187,35 @@ def candidate_assign_tiled(x: jax.Array, ctab: jax.Array, csqtab: jax.Array,
     nb = n // bn
     assert rowsel.shape == (nb,) and skip.shape == (nb,)
 
-    grid = (nb, knp // bkn)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=grid,
+        grid=(nb, knp // bkn),
         in_specs=[
             pl.BlockSpec((bn, d), lambda i, j, rs, sk: (i, 0)),
             # the gather: candidate slab j of table row rs[i], one DMA of
             # bkn contiguous candidate centers (zero row when skipped)
             pl.BlockSpec((1, bkn, d),
                          lambda i, j, rs, sk: (rs[i] * (1 - sk[i]), j, 0)),
-            pl.BlockSpec((1, bkn),
-                         lambda i, j, rs, sk: (rs[i] * (1 - sk[i]), j)),
-            pl.BlockSpec((1, bkn),
-                         lambda i, j, rs, sk: (rs[i] * (1 - sk[i]), j)),
-            pl.BlockSpec((bn,), lambda i, j, rs, sk: (i,)),
-            pl.BlockSpec((bn,), lambda i, j, rs, sk: (i,)),
-            pl.BlockSpec((bn,), lambda i, j, rs, sk: (i,)),
+            _cand_row_spec(knp),
         ],
-        out_specs=[
-            pl.BlockSpec((bn,), lambda i, j, rs, sk: (i,)),
-            pl.BlockSpec((bn,), lambda i, j, rs, sk: (i,)),
-            pl.BlockSpec((bn,), lambda i, j, rs, sk: (i,)),
-        ],
+        out_specs=[_row_spec(bn), _row_spec(bn), _row_spec(bn)],
         scratch_shapes=[
-            pltpu.VMEM((bn,), jnp.float32),
-            pltpu.VMEM((bn,), jnp.float32),
-            pltpu.VMEM((bn,), jnp.int32),
-            pltpu.VMEM((bn,), jnp.float32),
+            pltpu.VMEM((1, bn), jnp.float32),
+            pltpu.VMEM((knp, 1), jnp.float32),
         ],
     )
-    return pl.pallas_call(
+    rows = jax.ShapeDtypeStruct((nb, 1, bn), jnp.float32)
+    pos, d1, d2 = pl.pallas_call(
         _tiled_kernel,
         grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((n,), jnp.int32),
-            jax.ShapeDtypeStruct((n,), jnp.float32),
-            jax.ShapeDtypeStruct((n,), jnp.float32),
-        ],
+        out_shape=[jax.ShapeDtypeStruct((nb, 1, bn), jnp.int32), rows, rows],
         interpret=interpret,
-    )(rowsel, skip, x, ctab, csqtab, cidx, prev_a, prev_d1, prev_d2)
+    )(rowsel, skip, x, ctab, csqtab[:, None, :])
+    a = jnp.take_along_axis(cidx[rowsel], pos[:, 0, :], axis=1)
+    stale = jnp.repeat(skip != 0, bn)
+    return (jnp.where(stale, prev_a, a.reshape(n)).astype(jnp.int32),
+            jnp.where(stale, prev_d1, d1.reshape(n)),
+            jnp.where(stale, prev_d2, d2.reshape(n)))
 
 
 @functools.partial(jax.jit, static_argnames=("bn", "bkn", "interpret"))
@@ -230,52 +255,59 @@ def _int8_tiled_kernel(rowsel_ref, skip_ref,         # scalar prefetch (SMEM)
                        xq_ref, xsc_ref, xerr_ref, qtab_ref, qsc_ref,
                        qerr_ref, csq_ref,
                        surv_ref, nsv_ref, lbm_ref,
-                       lb_buf, ub_min, xhsq, *, r):
+                       lb_buf, ub_min, xhsq, qsc_col, qerr_col, csq_col,
+                       *, r):
+    # Same transposed tile as the f32 kernel: candidates on sublanes,
+    # points on lanes.
     i, j = pl.program_id(0), pl.program_id(1)
     nt = pl.num_programs(1)
-    bkn = qsc_ref.shape[1]
+    bkn = qtab_ref.shape[1]
     skipped = skip_ref[i] != 0
 
     @pl.when(j == 0)
     def _init():
         ub_min[...] = jnp.full_like(ub_min, PAD_SQDIST)
-        xq = xq_ref[...].astype(jnp.int32)
-        s = xsc_ref[...]
-        xhsq[...] = s * s * jnp.sum(xq * xq, axis=-1).astype(jnp.float32)
+        s = xsc_ref[0]                               # (1, bn)
+        # widen to int32 first: int8 -> float is the dequantization the
+        # int8 path budgets (DESIGN.md §13), and this is not one
+        xhsq[...] = s * s * lane_sqnorms(xq_ref[...].astype(jnp.int32))
+        qsc_col[...] = qsc_ref[0].T                  # (kn_pad, 1) each
+        qerr_col[...] = qerr_ref[0].T
+        csq_col[...] = csq_ref[0].T
 
     @pl.when(jnp.logical_not(skipped))
     def _compute():
-        xq = xq_ref[...]                             # (bn, d) int8
-        qt = qtab_ref[0]                             # (bkn, d) int8 slab
-        cross = jax.lax.dot_general(xq, qt, (((1,), (1,)), ((), ())),
+        cross = jax.lax.dot_general(qtab_ref[0], xq_ref[...],
+                                    (((1,), (1,)), ((), ())),
                                     preferred_element_type=jnp.int32)
-        sc = xsc_ref[...][:, None] * qsc_ref[0][None, :]
+        tile = _tile_rows(j, bkn)
+        sc = xsc_ref[0] * qsc_col[tile, :]           # (bkn, bn)
         dist = jnp.maximum(
-            xhsq[...][:, None] - 2.0 * sc * cross.astype(jnp.float32)
-            + csq_ref[0][None, :], 0.0)
+            xhsq[...] - 2.0 * sc * cross.astype(jnp.float32)
+            + csq_col[tile, :], 0.0)
         shat = jnp.sqrt(dist)                        # approx true distance
-        rc = qerr_ref[0]                             # exact candidate radii
-        lb_buf[:, pl.ds(j * bkn, bkn)] = shat - rc[None, :]
-        ub_min[...] = jnp.minimum(ub_min[...],
-                                  jnp.min(shat + rc[None, :], axis=1))
+        rc = qerr_col[tile, :]                       # exact candidate radii
+        lb_buf[tile, :] = shat - rc
+        ub_min[...] = jnp.minimum(
+            ub_min[...], jnp.min(shat + rc, axis=0, keepdims=True))
 
     @pl.when(j == nt - 1)
     def _flush():
-        rx = xerr_ref[...]                           # exact query radius
-        lb = lb_buf[...]
-        cut = (ub_min[...] + 2.0 * rx)[:, None]
-        mask = jnp.logical_and(lb <= cut,
-                               jnp.logical_not(skipped))
-        nsv = jnp.sum(mask.astype(jnp.int32), axis=1)
-        pos = jnp.cumsum(mask.astype(jnp.int32), axis=1) - 1
-        iota = jax.lax.broadcasted_iota(jnp.int32, mask.shape, 1)
+        lb = lb_buf[...]                             # (kn_pad, bn)
+        cut = ub_min[...] + 2.0 * xerr_ref[0]        # exact query radius
+        mask = jnp.logical_and(lb <= cut, jnp.logical_not(skipped))
+        nsv_ref[0] = jnp.sum(mask.astype(jnp.int32), axis=0, keepdims=True)
+        # survivor s is the s-th set row: peel the first set row r times
+        knp = mask.shape[0]
+        row = jax.lax.broadcasted_iota(jnp.int32, mask.shape, 0)
+        left = mask
         for s in range(r):                           # static unroll
-            sel = jnp.logical_and(mask, pos == s)
-            col = jnp.sum(jnp.where(sel, iota, 0), axis=1)
-            surv_ref[:, s] = jnp.where(s < nsv, col, -1)
-        nsv_ref[...] = nsv
-        rest = jnp.min(jnp.where(mask, PAD_SQDIST, lb), axis=1)
-        lbm_ref[...] = jnp.where(skipped, PAD_SQDIST, rest)
+            col = jnp.min(jnp.where(left, row, knp), axis=0, keepdims=True)
+            surv_ref[0, s:s + 1, :] = jnp.where(col < knp, col, -1)
+            left = jnp.logical_and(left, row != col)
+        rest = jnp.min(jnp.where(mask, PAD_SQDIST, lb), axis=0,
+                       keepdims=True)
+        lbm_ref[0] = jnp.where(skipped, PAD_SQDIST, rest)
 
 
 @functools.partial(jax.jit, static_argnames=("bn", "bkn", "r", "interpret"))
@@ -310,45 +342,47 @@ def candidate_assign_int8_tiled(xq: jax.Array, xsc: jax.Array,
     nb = n // bn
     assert rowsel.shape == (nb,) and skip.shape == (nb,)
 
-    grid = (nb, knp // bkn)
     kern = functools.partial(_int8_tiled_kernel, r=r)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=grid,
+        grid=(nb, knp // bkn),
         in_specs=[
             pl.BlockSpec((bn, d), lambda i, j, rs, sk: (i, 0)),
-            pl.BlockSpec((bn,), lambda i, j, rs, sk: (i,)),
-            pl.BlockSpec((bn,), lambda i, j, rs, sk: (i,)),
+            _row_spec(bn),
+            _row_spec(bn),
             pl.BlockSpec((1, bkn, d),
                          lambda i, j, rs, sk: (rs[i] * (1 - sk[i]), j, 0)),
-            pl.BlockSpec((1, bkn),
-                         lambda i, j, rs, sk: (rs[i] * (1 - sk[i]), j)),
-            pl.BlockSpec((1, bkn),
-                         lambda i, j, rs, sk: (rs[i] * (1 - sk[i]), j)),
-            pl.BlockSpec((1, bkn),
-                         lambda i, j, rs, sk: (rs[i] * (1 - sk[i]), j)),
+            _cand_row_spec(knp),
+            _cand_row_spec(knp),
+            _cand_row_spec(knp),
         ],
         out_specs=[
-            pl.BlockSpec((bn, r), lambda i, j, rs, sk: (i, 0)),
-            pl.BlockSpec((bn,), lambda i, j, rs, sk: (i,)),
-            pl.BlockSpec((bn,), lambda i, j, rs, sk: (i,)),
+            pl.BlockSpec((1, r, bn), lambda i, j, rs, sk: (i, 0, 0)),
+            _row_spec(bn),
+            _row_spec(bn),
         ],
         scratch_shapes=[
-            pltpu.VMEM((bn, knp), jnp.float32),
-            pltpu.VMEM((bn,), jnp.float32),
-            pltpu.VMEM((bn,), jnp.float32),
+            pltpu.VMEM((knp, bn), jnp.float32),
+            pltpu.VMEM((1, bn), jnp.float32),
+            pltpu.VMEM((1, bn), jnp.float32),
+            pltpu.VMEM((knp, 1), jnp.float32),
+            pltpu.VMEM((knp, 1), jnp.float32),
+            pltpu.VMEM((knp, 1), jnp.float32),
         ],
     )
-    return pl.pallas_call(
+    surv, nsv, lbm = pl.pallas_call(
         kern,
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((n, r), jnp.int32),
-            jax.ShapeDtypeStruct((n,), jnp.int32),
-            jax.ShapeDtypeStruct((n,), jnp.float32),
+            jax.ShapeDtypeStruct((nb, r, bn), jnp.int32),
+            jax.ShapeDtypeStruct((nb, 1, bn), jnp.int32),
+            jax.ShapeDtypeStruct((nb, 1, bn), jnp.float32),
         ],
         interpret=interpret,
-    )(rowsel, skip, xq, xsc, xerr, qtab, qsc, qerrtab, csqtab)
+    )(rowsel, skip, xq, block_rows(xsc, bn), block_rows(xerr, bn), qtab,
+      qsc[:, None, :], qerrtab[:, None, :], csqtab[:, None, :])
+    return (surv.transpose(0, 2, 1).reshape(n, r), nsv.reshape(n),
+            lbm.reshape(n))
 
 
 def tiled_grid_steps(n: int, kn: int, bn: int, bkn: int) -> int:

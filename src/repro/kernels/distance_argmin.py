@@ -16,37 +16,33 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
+
+from .candidate_assign import first_min_rows, lane_sqnorms
 
 
-def _kernel(x_ref, c_ref, csq_ref, a_ref, d_ref, best_d, best_a):
+def _kernel(x_ref, c_ref, a_ref, d_ref):
+    # Transposed tile: centers on sublanes, points on lanes, so the
+    # running (min, argmin) is a lane-dense (1, bn) row held in the
+    # output blocks across the k axis.
     j = pl.program_id(1)
-    nk = pl.num_programs(1)
 
     @pl.when(j == 0)
     def _init():
-        best_d[...] = jnp.full_like(best_d, jnp.inf)
-        best_a[...] = jnp.zeros_like(best_a)
+        d_ref[0] = jnp.full_like(d_ref[0], jnp.inf)
+        a_ref[0] = jnp.zeros_like(a_ref[0])
 
     x = x_ref[...]                                   # (bn, d)
     c = c_ref[...]                                   # (bk, d)
-    cross = jax.lax.dot_general(x, c, (((1,), (1,)), ((), ())),
+    # HIGHEST: the exact argmin; a TPU's default f32 matmul is one bf16 pass
+    cross = jax.lax.dot_general(c, x, (((1,), (1,)), ((), ())),
+                                precision=jax.lax.Precision.HIGHEST,
                                 preferred_element_type=jnp.float32)
-    xsq = jnp.sum(x * x, axis=-1, keepdims=True)     # (bn, 1)
-    dist = jnp.maximum(xsq - 2.0 * cross + csq_ref[...], 0.0)   # (bn, bk)
-
-    loc = jnp.argmin(dist, axis=1)                   # (bn,)
-    dmin = jnp.min(dist, axis=1)
-    bk = c.shape[0]
-    glob = (j * bk + loc).astype(jnp.int32)
-    better = dmin < best_d[...]
-    best_d[...] = jnp.where(better, dmin, best_d[...])
-    best_a[...] = jnp.where(better, glob, best_a[...])
-
-    @pl.when(j == nk - 1)
-    def _flush():
-        a_ref[...] = best_a[...]
-        d_ref[...] = best_d[...]
+    csq = jnp.sum(c * c, axis=1, keepdims=True)      # (bk, 1)
+    dist = jnp.maximum(lane_sqnorms(x) - 2.0 * cross + csq, 0.0)
+    dmin, loc, _ = first_min_rows(dist)
+    better = dmin < d_ref[0]
+    a_ref[0] = jnp.where(better, j * c.shape[0] + loc, a_ref[0])
+    d_ref[0] = jnp.minimum(d_ref[0], dmin)
 
 
 @functools.partial(jax.jit,
@@ -60,28 +56,20 @@ def distance_argmin(x: jax.Array, c: jax.Array, *, bn: int = 256,
     n, d = x.shape
     k = c.shape[0]
     assert n % bn == 0 and k % bk == 0, (n, bn, k, bk)
-    csq = jnp.sum(c * c, axis=-1)[None, :]           # (1, k)
-
-    grid = (n // bn, k // bk)
-    return pl.pallas_call(
+    nb = n // bn
+    row = pl.BlockSpec((1, 1, bn), lambda i, j: (i, 0, 0))
+    a, dist = pl.pallas_call(
         _kernel,
-        grid=grid,
+        grid=(nb, k // bk),
         in_specs=[
             pl.BlockSpec((bn, d), lambda i, j: (i, 0)),
             pl.BlockSpec((bk, d), lambda i, j: (j, 0)),
-            pl.BlockSpec((1, bk), lambda i, j: (0, j)),
         ],
-        out_specs=[
-            pl.BlockSpec((bn,), lambda i, j: (i,)),
-            pl.BlockSpec((bn,), lambda i, j: (i,)),
-        ],
+        out_specs=[row, row],
         out_shape=[
-            jax.ShapeDtypeStruct((n,), jnp.int32),
-            jax.ShapeDtypeStruct((n,), jnp.float32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((bn,), jnp.float32),
-            pltpu.VMEM((bn,), jnp.int32),
+            jax.ShapeDtypeStruct((nb, 1, bn), jnp.int32),
+            jax.ShapeDtypeStruct((nb, 1, bn), jnp.float32),
         ],
         interpret=interpret,
-    )(x, c, csq)
+    )(x, c)
+    return a.reshape(n), dist.reshape(n)

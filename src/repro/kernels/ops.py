@@ -1,7 +1,7 @@
 """jit'd public wrappers around the Pallas kernels: padding, block-size
-selection (VMEM budget), cluster-grouped layout construction, and CPU
-fallback (interpret=True) so the same call sites run in this container and
-on real TPUs.
+selection (VMEM budget), cluster-grouped layout construction, and the
+call-time choice between compiled kernels (TPU) and the Pallas
+interpreter (any other backend), so the same call sites run on both.
 """
 from __future__ import annotations
 
@@ -24,8 +24,15 @@ from .center_knn import center_knn, center_sqdist
 from .distance_argmin import distance_argmin
 from .segmented_scan import segmented_scan as _segmented_scan_kernel
 
-_ON_TPU = jax.default_backend() == "tpu"
 _VMEM_BUDGET = 12 * 2 ** 20 // 4          # ~12 MiB of f32 working set
+
+
+def resolve_interpret(interpret: bool | None = None) -> bool:
+    """The kernels' ``interpret`` flag, resolved when the call runs (never
+    at import, which would start a backend): an explicit value wins;
+    ``None`` compiles the kernels on a TPU and runs them in the Pallas
+    interpreter on any other backend."""
+    return jax.default_backend() != "tpu" if interpret is None else interpret
 
 
 def choose_blocks(d: int, k: int):
@@ -79,7 +86,7 @@ def _pad_rows(x, mult):
 def assign_nearest_pallas(x: jax.Array, c: jax.Array,
                           interpret: bool | None = None):
     """Drop-in fused assignment: (n,d),(k,d) -> (a (n,), sqdist (n,))."""
-    interpret = (not _ON_TPU) if interpret is None else interpret
+    interpret = resolve_interpret(interpret)
     n, d = x.shape
     k = c.shape[0]
     bn, bk = choose_blocks(d, k)
@@ -422,7 +429,7 @@ def quantized_scan_rerank(xf: jax.Array, xq: jax.Array, xsc: jax.Array,
     fallback (n,) bool); d2_sq is the exact second-best among survivors
     floored by the non-survivor margin bound — a valid (possibly looser)
     Hamerly lower bound, never an invalid one."""
-    interpret = (not _ON_TPU) if interpret is None else interpret
+    interpret = resolve_interpret(interpret)
     n, d = xf.shape
     nb = n // bn
     # exact per-row residual norms: the margin's query radius (the f32
@@ -507,7 +514,7 @@ def segmented_scan(x: jax.Array, w: jax.Array, block2seg: jax.Array,
     """Segmented inclusive scan of (x, ||x||^2, 1) over the cluster-grouped
     layout (see kernels/segmented_scan.py for the contract); interpret mode
     auto-selected off-TPU."""
-    interpret = (not _ON_TPU) if interpret is None else interpret
+    interpret = resolve_interpret(interpret)
     return _segmented_scan_kernel(x, w, block2seg, bn=bn, interpret=interpret)
 
 
@@ -528,7 +535,7 @@ def k2_assign_grouped(x: jax.Array, c: jax.Array, neighbors: jax.Array,
     Returns updated (a, sqdist1, sqdist2) in original point order; entries
     of skipped blocks keep their prev values exactly.
     """
-    interpret = (not _ON_TPU) if interpret is None else interpret
+    interpret = resolve_interpret(interpret)
     cidx = pad_candidates(neighbors.astype(jnp.int32), bkn)
     ctab, csqtab = candidate_tables(c, cidx)
     safe_perm = jnp.maximum(perm, 0)
@@ -557,7 +564,7 @@ __all__ = ["assign_nearest_pallas", "bounded_predict_assign",
            "k2_assign_grouped", "k2_bounded_assign", "pad_candidates",
            "plan_layout_evict", "plan_layout_repair", "quant",
            "quantized_scan_rerank",
-           "resident_capacity", "resident_regroup",
+           "resident_capacity", "resolve_interpret", "resident_regroup",
            "rowwise_grid_steps",
            "scatter_from_grouped", "segmented_scan", "select_clusters",
            "tiled_grid_steps"]

@@ -26,11 +26,17 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .candidate_assign import block_rows, lane_sqnorms
+
+# the in-block prefix sums are triangular matmuls: at a TPU's default
+# f32 precision (one bf16 pass) every summand would be rounded
+_HI = jax.lax.Precision.HIGHEST
+
 
 def _kernel(b2s_ref,                                  # scalar prefetch (SMEM)
             x_ref, w_ref,
             csum_ref, qsum_ref, cnt_ref,
-            carry_x, carry_s):
+            carry_x, carry_q, carry_c):
     i = pl.program_id(0)
     seg = b2s_ref[i]
     prev = b2s_ref[jnp.maximum(i - 1, 0)]
@@ -39,21 +45,34 @@ def _kernel(b2s_ref,                                  # scalar prefetch (SMEM)
     @pl.when(reset)
     def _():
         carry_x[...] = jnp.zeros_like(carry_x)
-        carry_s[0] = 0.0
-        carry_s[1] = 0.0
+        carry_q[...] = jnp.zeros_like(carry_q)
+        carry_c[...] = jnp.zeros_like(carry_c)
 
     x = x_ref[...]                                    # (bn, d)
-    w = w_ref[...]                                    # (bn,)
-    xw = x * w[:, None]
-    cx = jnp.cumsum(xw, axis=0) + carry_x[...]
-    cq = jnp.cumsum(jnp.sum(xw * x, axis=-1)) + carry_s[0]
-    cc = jnp.cumsum(w) + carry_s[1]
+    w = w_ref[0]                                      # (1, bn) row weights
+    bn = x.shape[0]
+    # the in-block inclusive scans as triangular matmuls (Mosaic has no
+    # cumsum): lower[r, r'] = w[r'] for r' <= r sums rows down the block,
+    # upper[r', r] = [r' <= r] sums a lane row left to right
+    r_ = jax.lax.broadcasted_iota(jnp.int32, (bn, bn), 0)
+    c_ = jax.lax.broadcasted_iota(jnp.int32, (bn, bn), 1)
+    lower = jnp.where(c_ <= r_, w, 0.0)
+    upper = (r_ <= c_).astype(jnp.float32)
+    cx = jax.lax.dot_general(lower, x, (((1,), (0,)), ((), ())),
+                             precision=_HI,
+                             preferred_element_type=jnp.float32) \
+        + carry_x[...]
+    lane_scan = functools.partial(
+        jax.lax.dot_general, dimension_numbers=(((1,), (0,)), ((), ())),
+        precision=_HI, preferred_element_type=jnp.float32)
+    cq = lane_scan(w * lane_sqnorms(x), upper) + carry_q[...]
+    cc = lane_scan(w, upper) + carry_c[...]
     csum_ref[...] = cx
-    qsum_ref[...] = cq
-    cnt_ref[...] = cc
-    carry_x[...] = cx[-1:, :]
-    carry_s[0] = cq[-1]
-    carry_s[1] = cc[-1]
+    qsum_ref[0] = cq
+    cnt_ref[0] = cc
+    carry_x[...] = cx[bn - 1:bn, :]
+    carry_q[...] = cq[:, bn - 1:bn]
+    carry_c[...] = cc[:, bn - 1:bn]
 
 
 @functools.partial(jax.jit, static_argnames=("bn", "interpret"))
@@ -72,30 +91,23 @@ def segmented_scan(x: jax.Array, w: jax.Array, block2seg: jax.Array,
     nb = r // bn
     assert block2seg.shape == (nb,)
 
+    row = pl.BlockSpec((1, 1, bn), lambda i, b2s: (i, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(nb,),
-        in_specs=[
-            pl.BlockSpec((bn, d), lambda i, b2s: (i, 0)),
-            pl.BlockSpec((bn,), lambda i, b2s: (i,)),
-        ],
-        out_specs=[
-            pl.BlockSpec((bn, d), lambda i, b2s: (i, 0)),
-            pl.BlockSpec((bn,), lambda i, b2s: (i,)),
-            pl.BlockSpec((bn,), lambda i, b2s: (i,)),
-        ],
+        in_specs=[pl.BlockSpec((bn, d), lambda i, b2s: (i, 0)), row],
+        out_specs=[pl.BlockSpec((bn, d), lambda i, b2s: (i, 0)), row, row],
         scratch_shapes=[
             pltpu.VMEM((1, d), jnp.float32),
-            pltpu.SMEM((2,), jnp.float32),
+            pltpu.VMEM((1, 1), jnp.float32),
+            pltpu.VMEM((1, 1), jnp.float32),
         ],
     )
-    return pl.pallas_call(
+    rows = jax.ShapeDtypeStruct((nb, 1, bn), jnp.float32)
+    csum, qsum, cnt = pl.pallas_call(
         _kernel,
         grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((r, d), jnp.float32),
-            jax.ShapeDtypeStruct((r,), jnp.float32),
-            jax.ShapeDtypeStruct((r,), jnp.float32),
-        ],
+        out_shape=[jax.ShapeDtypeStruct((r, d), jnp.float32), rows, rows],
         interpret=interpret,
-    )(block2seg, x, w)
+    )(block2seg, x, block_rows(w, bn))
+    return csum, qsum.reshape(r), cnt.reshape(r)

@@ -35,6 +35,19 @@ def make_debug_cluster_mesh():
     return jax.make_mesh((n,), ("data",))
 
 
+def auto_axes(mesh):
+    """``mesh`` over the same devices with every axis Auto-typed.
+
+    ``jax.make_mesh`` types its axes Explicit, under which a gather of a
+    sharded array outside ``shard_map`` must name its output sharding; the
+    sharded fits here leave sharding outside ``shard_map`` to the
+    compiler."""
+    auto = (jax.sharding.AxisType.Auto,) * len(mesh.axis_names)
+    if tuple(mesh.axis_types) == auto:
+        return mesh
+    return jax.sharding.Mesh(mesh.devices, mesh.axis_names, axis_types=auto)
+
+
 def dp_axes(mesh) -> tuple:
     """The data-parallel axes of a mesh ('pod' included when present)."""
     return tuple(a for a in mesh.axis_names if a in ("pod", "data"))

@@ -177,7 +177,7 @@ def cluster_major_decode_attention(q, kt, vt, centroids, sizes, top_p: int,
     (max, sum, acc) — collective volume O(B*H*dh), independent of S."""
     from jax.interpreters import pxla
 
-    from ..compat import shard_map
+    from jax import shard_map
 
     B, H, dh = q.shape
     Hkv, kc, cap = centroids.shape[1], centroids.shape[2], kt.shape[3]

@@ -126,8 +126,7 @@ def test_seeded_f64_leak_in_int8_region_is_k2l102():
         # dequantize straight to f64 — both prongs of the dtype rule
         return jnp.sum(xq.astype(jnp.float64))
 
-    from jax.experimental import enable_x64
-    with enable_x64():
+    with jax.enable_x64(True):
         fs = jaxpr_audit.audit_entry(
             _entry(hot, (jnp.zeros((8, 4), jnp.int8),),
                    int8_region=True, sanctioned_dequants=0))
@@ -170,7 +169,7 @@ def test_seeded_trace_failure_is_k2l100_and_alt_signature_k2l103():
 
 
 def test_seeded_collective_in_collective_free_entry_is_k2l104():
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
     mesh = jax.make_mesh((1,), ("data",))
 

@@ -133,6 +133,7 @@ r_rs = fit_distributed_k2means(x, k, kn, mesh, key, max_iters=25,
 out["resident_driver_same"] = bool((np.asarray(r_rs.assignment)
                                     == np.asarray(ref_p.assignment)).all()
                                    and r_rs.iterations == ref_p.iterations)
+out["resident_placement"] = r_rs.placement
 # sparse repairs move fewer bytes than the rebuild engine's full regroup
 cnt_rb = OpCounter()
 fit_distributed_k2means(x, k, kn, mesh, key, max_iters=25,
@@ -148,6 +149,7 @@ rapi = fit(x, k, mesh=mesh, kn=kn, max_iters=10, init="random",
 out["api_shapes"] = [list(np.asarray(rapi.centers).shape),
                      list(np.asarray(rapi.assignment).shape)]
 out["api_ops"] = capi.total
+out["api_placement"] = rapi.placement
 print("RESULT " + json.dumps(out))
 """
 
@@ -245,6 +247,15 @@ def test_engine_step_matches_single_device():
     assert 0 <= out["resident_repair_moved_max"] < 1024
     assert out["resident_driver_same"]
     assert out["resident_bytes_win"]
+    # the mesh fit reports where its row-sharded state lived: an equal
+    # share on each of the 4 devices
+    for place, names in ((out["resident_placement"],
+                          ("xg", "pid", "ug", "lo_g")),
+                         (out["api_placement"], ("u", "lo"))):
+        assert sorted(place) == sorted(names)
+        for rows in place.values():
+            assert len(rows) == 4 and len(set(rows.values())) == 1, rows
+    assert sum(out["api_placement"]["u"].values()) == 1024
 
 
 def test_sharded_gdi_seeding_energy():

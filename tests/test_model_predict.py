@@ -240,6 +240,53 @@ def test_predict_only_model_without_arena():
     assert model.n_rows == 0
 
 
+def test_router_lists_every_center_of_a_dense_region():
+    """Centers piled into one region make one router group far larger
+    than the mean: the default cap grows to hold it, so every center is
+    in some member list and predicting at the centers themselves finds
+    each of them."""
+    from repro.core.lloyd import KMeansResult
+    from repro.core.model import _default_cap, _default_groups
+    k, d = 256, 16
+    kd, ks = jax.random.split(jax.random.PRNGKey(5))
+    dense = 0.05 * jax.random.normal(kd, (192, d))
+    spread = 4.0 * jax.random.normal(ks, (k - 192, d))
+    c = jnp.concatenate([dense, spread])
+    res = KMeansResult(c, jnp.arange(k, dtype=jnp.int32), 0.0, 0, 0.0, [])
+    model = KMeansModel.from_result(res, kn=8)
+    g = _default_groups(k)
+    assert model.route_cap > _default_cap(k, g, 8)
+    listed = np.zeros(k, bool)
+    listed[np.asarray(model.router.members).ravel()] = True
+    assert listed.all()
+    assert (np.asarray(model.predict(c)) == np.arange(k)).all()
+
+
+def test_router_lists_every_center_after_drift_refresh():
+    """partial_fit pulls three quarters of the centers into one dense
+    region; the routers rebuilt at the refreshes widen their lists past
+    the width the model was built with, so every center is still listed
+    and predicting at each center finds it."""
+    from repro.core.lloyd import KMeansResult
+    k, d, rows = 256, 16, 8
+    c = 4.0 * jax.random.normal(jax.random.PRNGKey(7), (k, d))
+    res = KMeansResult(c, jnp.arange(k, dtype=jnp.int32), 0.0, 0, 0.0, [])
+    model = KMeansModel.from_result(res, kn=8, refresh_every=1, decay=0.01)
+    cap0 = model.route_cap
+    pile = np.arange(192)
+    for _ in range(6):
+        # rows at half of each piled center's position pull it halfway in
+        target = 0.5 * np.asarray(model.centers)[pile]
+        model.partial_fit(jnp.asarray(np.repeat(target, rows, axis=0)))
+    listed = np.zeros(k, bool)
+    listed[np.asarray(model.router.members).ravel()] = True
+    assert listed.all()
+    assert model.route_cap > cap0
+    assert np.median(np.linalg.norm(np.asarray(model.centers)[pile],
+                                    axis=1)) < 1.0
+    assert (np.asarray(model.predict(model.centers)) == np.arange(k)).all()
+
+
 def test_kv_partial_fit_folds_ring():
     """The KV-domain partial_fit absorbs live ring rows into the
     cluster-major tables with running-mean centroid updates and resets
