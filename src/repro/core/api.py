@@ -26,7 +26,8 @@ INITS = ("random", "kmeanspp", "gdi", "gdi_host", "gdi_device",
 
 def initialize(x: jax.Array, k: int, init: str, key: jax.Array,
                counter: OpCounter, backend: str | None = None) -> jax.Array:
-    """Initial centers, (k, d).
+    """Initial centers, (k, d), under the profiler span ``kmeans.init``
+    (its ``rounds`` and ``leaves`` set by the divisive inits).
 
     ``init="gdi"`` resolves to the frontier-batched device GDI when the
     fit runs on the Pallas fast path (``backend="pallas"``) so the whole
@@ -35,19 +36,23 @@ def initialize(x: jax.Array, k: int, init: str, key: jax.Array,
     one explicitly. The divisive inits' leaf assignments are dropped:
     k²-means starts from the exact assignment (:func:`fit_k2means`).
     """
-    if init == "random":
-        return random_init(x, k, key, counter)
-    if init == "kmeanspp":
-        return kmeanspp_init(x, k, key, counter)
     if init == "gdi":
         init = "gdi_device" if backend == "pallas" else "gdi_host"
-    if init == "gdi_host":
-        return gdi_init(x, k, key, counter=counter)[0]
-    if init == "gdi_device":
-        return gdi_device_init(x, k, key, counter=counter)[0]
-    if init == "gdi_parallel":
-        return gdi_parallel_init(x, k, key, counter=counter)[0]
-    raise ValueError(f"unknown init {init!r}; expected one of {INITS}")
+    with jax.profiler.TraceAnnotation("kmeans.init") as span:
+        if init == "random":
+            return random_init(x, k, key, counter)
+        if init == "kmeanspp":
+            return kmeanspp_init(x, k, key, counter)
+        divisive = {"gdi_host": gdi_init, "gdi_device": gdi_device_init,
+                    "gdi_parallel": gdi_parallel_init}.get(init)
+        if divisive is None:
+            raise ValueError(f"unknown init {init!r}; expected one of "
+                             f"{INITS}")
+        info = {}
+        centers = divisive(x, k, key, counter=counter, info=info)[0]
+        if span.is_enabled():
+            span.set_metadata(**info)
+        return centers
 
 
 def fit(x: jax.Array, k: int, *, method: str = "k2means", init: str = "gdi",
@@ -100,44 +105,65 @@ def fit(x: jax.Array, k: int, *, method: str = "k2means", init: str = "gdi",
     fitting (quarantine, counted on ``counter.sanitized_rows``); "none"
     skips the check (DESIGN.md §11.5).
     """
-    key = key if key is not None else jax.random.PRNGKey(0)
     counter = counter or OpCounter()
-    k_init, k_fit = jax.random.split(key)
-    x = jnp.asarray(x, jnp.float32)
-    if x.ndim != 2:
-        raise ValueError(f"x must be 2-D (n, d), got shape {x.shape}")
-    if validate not in ("raise", "sanitize", "none"):
-        raise ValueError(f"validate must be 'raise' | 'sanitize' | "
-                         f"'none', got {validate!r}")
-    if validate != "none":
-        import numpy as np
-        bad = ~jnp.isfinite(x).all(axis=1)
-        n_bad = int(jnp.sum(bad))
-        if n_bad:
-            if validate == "raise":
-                idx = np.flatnonzero(np.asarray(bad))[:8]
-                raise ValueError(
-                    f"fit input: {n_bad} non-finite rows (first at "
-                    f"{idx.tolist()}); pass validate='sanitize' to zero "
-                    f"them")
-            x = jnp.where(bad[:, None], 0.0, x)
-            counter.count_sanitized_rows(n_bad)
-
-    def done(result: KMeansResult) -> KMeansResult:
+    reads0 = counter.host_reads
+    with jax.profiler.TraceAnnotation("kmeans.fit") as span:
+        key = key if key is not None else jax.random.PRNGKey(0)
+        x = jnp.asarray(x, jnp.float32)
+        if x.ndim != 2:
+            raise ValueError(f"x must be 2-D (n, d), got shape {x.shape}")
+        if validate not in ("raise", "sanitize", "none"):
+            raise ValueError(f"validate must be 'raise' | 'sanitize' | "
+                             f"'none', got {validate!r}")
+        if validate != "none":
+            x = _validated(x, validate, counter)
+        result = _fit_placed(x, k, method, init, key, counter, mesh,
+                             max_iters=max_iters, kn=kn, m=m, batch=batch,
+                             minibatch_iters=minibatch_iters, **kw)
+        if span.is_enabled():
+            span.set_metadata(method=method, init=init, n=x.shape[0],
+                              d=x.shape[1], k=k, kn=kn,
+                              host_reads=counter.host_reads - reads0)
         if profile:
             result.profile = counter.profile()
-        if return_model:
-            from .model import KMeansModel
-            # the mesh placement defaults backend to "pallas"; the served
-            # model follows the backend the fit actually ran on
-            backend = kw.get("backend") or \
-                ("pallas" if mesh is not None else "xla")
-            model = KMeansModel.from_result(
-                result, x, kn=min(kn, k), capacity=model_capacity,
-                backend=backend, interpret=kw.get("interpret"))
-            return result, model
-        return result
+        if not return_model:
+            return result
+        from .model import KMeansModel
+        # the mesh placement defaults backend to "pallas"; the served model
+        # follows the backend the fit actually ran on
+        backend = kw.get("backend") or \
+            ("pallas" if mesh is not None else "xla")
+        return result, KMeansModel.from_result(
+            result, x, kn=min(kn, k), capacity=model_capacity,
+            backend=backend, interpret=kw.get("interpret"))
 
+
+def _validated(x: jax.Array, validate: str,
+               counter: OpCounter) -> jax.Array:
+    """``x`` checked for non-finite rows (one host read, under the span
+    ``kmeans.validate``): "raise" rejects them, "sanitize" zeroes them."""
+    with jax.profiler.TraceAnnotation("kmeans.validate") as span:
+        bad = ~jnp.isfinite(x).all(axis=1)
+        n_bad = int(jnp.sum(bad))
+        counter.host_reads += 1
+        if span.is_enabled():
+            span.set_metadata(bad_rows=n_bad)
+    if not n_bad:
+        return x
+    if validate == "raise":
+        import numpy as np
+        idx = np.flatnonzero(np.asarray(bad))[:8]
+        raise ValueError(
+            f"fit input: {n_bad} non-finite rows (first at "
+            f"{idx.tolist()}); pass validate='sanitize' to zero them")
+    counter.count_sanitized_rows(n_bad)
+    return jnp.where(bad[:, None], 0.0, x)
+
+
+def _fit_placed(x, k, method, init, key, counter, mesh, *, max_iters, kn,
+                m, batch, minibatch_iters, **kw) -> KMeansResult:
+    """The fit itself, on one device or on ``mesh`` (see :func:`fit`)."""
+    k_init, k_fit = jax.random.split(key)
     if mesh is not None:
         if method != "k2means":
             raise ValueError(
@@ -146,29 +172,29 @@ def fit(x: jax.Array, k: int, *, method: str = "k2means", init: str = "gdi",
         from .distributed import fit_distributed_k2means
         # k_init, as on the single-device path: init="random" from the
         # same seed samples the same centers under either placement
-        return done(fit_distributed_k2means(x, k, kn, mesh, k_init,
-                                            max_iters=max_iters, init=init,
-                                            counter=counter, **kw))
+        return fit_distributed_k2means(x, k, kn, mesh, k_init,
+                                       max_iters=max_iters, init=init,
+                                       counter=counter, **kw)
 
     centers = initialize(x, k, init, k_init, counter,
                          backend=kw.get("backend"))
 
     if method == "lloyd":
-        return done(fit_lloyd(x, centers, max_iters=max_iters,
-                              counter=counter, **kw))
+        return fit_lloyd(x, centers, max_iters=max_iters, counter=counter,
+                         **kw)
     if method == "elkan":
-        return done(fit_elkan(x, centers, max_iters=max_iters,
-                              counter=counter, **kw))
+        return fit_elkan(x, centers, max_iters=max_iters, counter=counter,
+                         **kw)
     if method == "k2means":
         # the exact start fit_k2means requires (its docstring)
-        assignment = assign_nearest(x, centers, counter)
-        return done(fit_k2means(x, centers, assignment, kn=kn,
-                                max_iters=max_iters, counter=counter, **kw))
+        with jax.profiler.TraceAnnotation("kmeans.exact_start"):
+            assignment = assign_nearest(x, centers, counter)
+        return fit_k2means(x, centers, assignment, kn=kn,
+                           max_iters=max_iters, counter=counter, **kw)
     if method == "minibatch":
-        return done(fit_minibatch(x, centers, k_fit, batch=batch,
-                                  iters=minibatch_iters, counter=counter,
-                                  **kw))
+        return fit_minibatch(x, centers, k_fit, batch=batch,
+                             iters=minibatch_iters, counter=counter, **kw)
     if method == "akm":
-        return done(fit_akm(x, centers, k_fit, m=m, max_iters=max_iters,
-                            counter=counter, **kw))
+        return fit_akm(x, centers, k_fit, m=m, max_iters=max_iters,
+                       counter=counter, **kw)
     raise ValueError(f"unknown method {method!r}; expected one of {METHODS}")

@@ -109,10 +109,11 @@ def projective_split(x: jax.Array, mask: jax.Array, key: jax.Array,
 
 def gdi_init(x: jax.Array, k: int, key: jax.Array, *,
              split_iters: int = 2,
-             counter: OpCounter | None = None):
+             counter: OpCounter | None = None, info: dict | None = None):
     """Algorithm 2: greedy divisive initialization.
 
-    Returns (centers (k, d), assignment (n,)).
+    Returns (centers (k, d), assignment (n,)); ``info``, when given, is
+    filled with the splits made (``rounds``) and the ``leaves`` reached.
     """
     counter = counter or OpCounter()
     n, d = x.shape
@@ -121,6 +122,7 @@ def gdi_init(x: jax.Array, k: int, key: jax.Array, *,
     mu = jnp.mean(x, axis=0)
     centers = [mu]
     energies = [float(jnp.sum(jnp.square(x - mu)))]
+    counter.host_reads += 1
     masks = [jnp.ones((n,), bool)]
     sizes = [n]
     counter.add_additions(n)  # initial mean
@@ -151,7 +153,10 @@ def gdi_init(x: jax.Array, k: int, key: jax.Array, *,
         centers.append(c_b)
         energies.append(float(phi_b))
         sizes.append(m - sa)
+        counter.host_reads += 3                 # sa, phi_a, phi_b
 
+    if info is not None:
+        info.update(rounds=len(centers) - 1, leaves=len(centers))
     centers_arr = jnp.stack(centers)
     if len(centers) < k:  # pathological tiny-n fallback: pad with copies
         reps = k - len(centers)
@@ -414,12 +419,40 @@ def _charge_round(counter: OpCounter, r: int, n: int, d: int,
         counter.add_sort(r, d)
 
 
+def _frontier_rounds(x, state, key, counter: OpCounter, *, k: int,
+                     bn: int, r: int, split_iters: int, impl: str,
+                     interpret: bool, frontier: float,
+                     max_rounds: int | None = None):
+    """Frontier rounds from ``state`` until ``k`` leaves, ``max_rounds``
+    rounds, or a round that splits nothing. Each round is the span
+    ``kmeans.init.round``: its dispatch and its one host read, the leaf
+    count. Returns (state, leaves, rounds run)."""
+    n, d = x.shape
+    nleaf, rounds = 1, 0
+    while nleaf < k and (max_rounds is None or rounds < max_rounds):
+        with jax.profiler.TraceAnnotation("kmeans.init.round") as span:
+            key, sub = jax.random.split(key)
+            state = gdi_round_step(x, *state, sub, k=k, bn=bn,
+                                   split_iters=split_iters, impl=impl,
+                                   interpret=interpret, frontier=frontier)
+            _charge_round(counter, r, n, d, split_iters)
+            new_nleaf = int(state[4])           # the round's one host read
+            counter.host_reads += 1
+            if span.is_enabled():
+                span.set_metadata(round=rounds, leaves=new_nleaf)
+        rounds += 1
+        if new_nleaf == nleaf:
+            break                               # nothing splittable left
+        nleaf = new_nleaf
+    return state, nleaf, rounds
+
+
 def gdi_device_init(x: jax.Array, k: int, key: jax.Array, *,
                     split_iters: int = 2,
                     counter: OpCounter | None = None,
                     bn: int | None = None, impl: str | None = None,
                     interpret: bool | None = None,
-                    frontier: float = 0.125):
+                    frontier: float = 0.125, info: dict | None = None):
     """Frontier-batched greedy divisive initialization, device-resident.
 
     Same algorithm as ``gdi_init`` (greedy: highest-energy leaves split
@@ -430,7 +463,8 @@ def gdi_device_init(x: jax.Array, k: int, key: jax.Array, *,
     two syncs each. impl: "pallas" routes the segmented scan through the
     Pallas kernel, "xla" through the segment_* reference (the off-TPU
     default — interpret-mode Pallas would serialize on the grid).
-    Returns (centers (k, d), assignment (n,)).
+    Returns (centers (k, d), assignment (n,)); ``info``, when given, is
+    filled with the ``rounds`` run and the ``leaves`` reached.
     """
     counter = counter or OpCounter()
     n, d = x.shape
@@ -441,19 +475,13 @@ def gdi_device_init(x: jax.Array, k: int, key: jax.Array, *,
     bn = bn or (choose_group_bn(n, k, d) if impl == "pallas" else 8)
     r = grouped_capacity(n, k, bn) * bn
 
-    state = _device_state(x, k)
     counter.add_additions(n)                    # initial mean
-    nleaf = 1
-    while nleaf < k:
-        key, sub = jax.random.split(key)
-        state = gdi_round_step(x, *state, sub, k=k, bn=bn,
-                               split_iters=split_iters, impl=impl,
-                               interpret=interpret, frontier=frontier)
-        _charge_round(counter, r, n, d, split_iters)
-        new_nleaf = int(state[4])               # the round's one host read
-        if new_nleaf == nleaf:
-            break                               # nothing splittable left
-        nleaf = new_nleaf
+    state, nleaf, rounds = _frontier_rounds(
+        x, _device_state(x, k), key, counter, k=k, bn=bn, r=r,
+        split_iters=split_iters, impl=impl, interpret=interpret,
+        frontier=frontier)
+    if info is not None:
+        info.update(rounds=rounds, leaves=nleaf)
     a, centers = state[0], state[1]
     if nleaf < k:   # pathological tiny-n fallback: pad with copies
         centers = jnp.where((jnp.arange(k) < nleaf)[:, None], centers,
@@ -514,7 +542,8 @@ def gdi_parallel_init(x: jax.Array, k: int, key: jax.Array, *,
                       split_iters: int = 2,
                       counter: OpCounter | None = None,
                       bn: int | None = None, impl: str | None = None,
-                      interpret: bool | None = None):
+                      interpret: bool | None = None,
+                      info: dict | None = None):
     """Round-parallel divisive variant (paper footnote 2): every round
     splits *all* current leaves at once — O(log2 k) rounds. (The
     distributed path seeds per shard through ``gdi_fixed_rounds`` with
@@ -522,7 +551,8 @@ def gdi_parallel_init(x: jax.Array, k: int, key: jax.Array, *,
     device round step as ``gdi_device_init`` with the frontier cap off,
     over a power-of-two slot capacity; if k is not a power of two the k
     highest-energy leaves are kept and the rest reassigned to the nearest
-    kept center.
+    kept center. ``info``, when given, is filled as by
+    :func:`gdi_device_init`.
     """
     counter = counter or OpCounter()
     n, d = x.shape
@@ -532,19 +562,14 @@ def gdi_parallel_init(x: jax.Array, k: int, key: jax.Array, *,
     bn = bn or (choose_group_bn(n, k2, d) if impl == "pallas" else 8)
     r = grouped_capacity(n, k2, bn) * bn
 
-    state = _device_state(x, k2)
     counter.add_additions(n)
-    nleaf = 1
-    for _ in range(math.ceil(math.log2(k2)) if k2 > 1 else 0):
-        key, sub = jax.random.split(key)
-        state = gdi_round_step(x, *state, sub, k=k2, bn=bn,
-                               split_iters=split_iters, impl=impl,
-                               interpret=interpret, frontier=1.0)
-        _charge_round(counter, r, n, d, split_iters)
-        new_nleaf = int(state[4])
-        if new_nleaf == nleaf:
-            break
-        nleaf = new_nleaf
+    state, nleaf, rounds = _frontier_rounds(
+        x, _device_state(x, k2), key, counter, k=k2, bn=bn, r=r,
+        split_iters=split_iters, impl=impl, interpret=interpret,
+        frontier=1.0,
+        max_rounds=math.ceil(math.log2(k2)) if k2 > 1 else 0)
+    if info is not None:
+        info.update(rounds=rounds, leaves=nleaf)
     a, centers, energies = state[0], state[1], state[2]
     if k2 == k:
         if nleaf < k:   # degenerate data stalled the rounds short of k

@@ -111,7 +111,9 @@ def k2means_pallas_step(x, c, a, u, lo, prev_neighbors, first, kn: int,
 class _MonitorLoop:
     """Deferred-host-read driver shared by the device-step fit loops:
     stats stay on device and are flushed (op/byte charged + convergence
-    checked) every ``monitor_every`` iterations (DESIGN.md §4.3)."""
+    checked) every ``monitor_every`` iterations (DESIGN.md §4.3). Each
+    flush is the span ``kmeans.iterate.flush``, whose attributes sum the
+    iterations it consumed."""
 
     def __init__(self, counter, *, n, d, k, kn, resident, precision="f32"):
         self.counter = counter
@@ -120,18 +122,32 @@ class _MonitorLoop:
         self.pending = []
         self.history = []
         self.it_done = 0
+        self.rows_recomputed = 0    # sum of n_need over consumed iterations
         self.converged = False
 
     def flush(self):
-        for stats in jax.device_get(self.pending):
-            self.it_done += 1
-            energy = charge_iteration(self.counter, stats=stats,
-                                      **self.args)
-            self.history.append((self.counter.snapshot(), float(energy)))
-            if self.it_done > 1 and int(stats[1]) == 0:
-                self.converged = True   # fixed point: later pending
-                break                   # iterations are identical, drop
-        self.pending.clear()
+        with jax.profiler.TraceAnnotation("kmeans.iterate.flush") as span:
+            got = jax.device_get(self.pending)
+            self.counter.host_reads += 1
+            used = 0
+            sums = np.zeros(4, np.int64)    # n_need, changed, moved, resorted
+            for stats in got:
+                used += 1
+                self.it_done += 1
+                energy = charge_iteration(self.counter, stats=stats,
+                                          **self.args)
+                self.history.append((self.counter.snapshot(), float(energy)))
+                sums += [int(stats[i]) for i in (0, 1, 3, 4)]
+                if self.it_done > 1 and int(stats[1]) == 0:
+                    self.converged = True   # fixed point: later pending
+                    break                   # iterations are identical, drop
+            self.rows_recomputed += int(sums[0])
+            if span.is_enabled():
+                span.set_metadata(
+                    iterations=used, n_need=int(sums[0]),
+                    changed=int(sums[1]), moved=int(sums[2]),
+                    resorted=int(sums[3]))
+            self.pending.clear()
 
 
 def _fit_k2means_engine(x, centers, assignment, *, kn, max_iters, counter,
@@ -182,57 +198,76 @@ def _fit_k2means_engine(x, centers, assignment, *, kn, max_iters, counter,
             centers = jnp.asarray(c_h)
             assignment = jnp.asarray(a_h)
             counter.count_repair("restore")
-    if resident:
-        state = sb.init_resident(x, w, centers, assignment)
-    else:
-        state = init_state(centers,
-                           jnp.asarray(assignment).astype(jnp.int32), kn)
-        if bnds is not None and bnds["nb"].shape == state.prev_nb.shape:
-            # restored Hamerly state: resume the gated trajectory
-            # bit-for-bit rather than forcing a full recompute
-            state = K2State(state.c, state.a, jnp.asarray(bnds["u"]),
-                            jnp.asarray(bnds["lo"]),
-                            jnp.asarray(bnds["nb"]), jnp.array(False))
-    guard = make_guard(sb, n) if guards else None
-    mon = _MonitorLoop(counter, n=n, d=d, k=k, kn=kn, resident=resident,
-                       precision=precision)
+    with jax.profiler.TraceAnnotation("kmeans.iterate") as span:
+        if resident:
+            with jax.profiler.TraceAnnotation("kmeans.iterate.build"):
+                state = sb.init_resident(x, w, centers, assignment)
+        else:
+            state = init_state(centers,
+                               jnp.asarray(assignment).astype(jnp.int32),
+                               kn)
+            if bnds is not None and \
+                    bnds["nb"].shape == state.prev_nb.shape:
+                # restored Hamerly state: resume the gated trajectory
+                # bit-for-bit rather than forcing a full recompute
+                state = K2State(state.c, state.a, jnp.asarray(bnds["u"]),
+                                jnp.asarray(bnds["lo"]),
+                                jnp.asarray(bnds["nb"]), jnp.array(False))
+        guard = make_guard(sb, n) if guards else None
+        mon = _MonitorLoop(counter, n=n, d=d, k=k, kn=kn,
+                           resident=resident, precision=precision)
 
-    for it in range(it0 + 1, max_iters + 1):
-        if inj is not None:
-            x, w, state = chaos_mod.apply_fit_faults(inj, it, x, w, state,
-                                                     resident)
-        state, stats = step(x, w, state)
-        mon.pending.append(tuple(stats))
-        if it % monitor_every == 0 or it == max_iters:
-            mon.flush()
-            healed = False
-            if guard is not None:
-                vio = np.asarray(jax.device_get(guard(state)))
-                bad_energy = bool(mon.history) and \
-                    not math.isfinite(mon.history[-1][1])
-                if vio.any() or bad_energy:
-                    if bad_energy and not vio.any():
-                        vio = np.array([0, 1, 0, 0])   # full-heal route
-                    x, w, state = heal_fit(x, w, state, sb, n, counter,
-                                           key, vio)
-                    mon.converged = False   # healed state must re-iterate
-                    healed = True
-            if ckpt is not None and not healed and ckpt.due(it):
-                if resident:
-                    ckpt.save(it, state.c, sb.final_assignment(state, n))
-                else:
-                    ckpt.save(it, state.c, state.a, u=state.u,
-                              lo=state.lo, nb=state.prev_nb)
-            if mon.converged:
-                break
+        for it in range(it0 + 1, max_iters + 1):
+            if inj is not None:
+                x, w, state = chaos_mod.apply_fit_faults(inj, it, x, w,
+                                                         state, resident)
+            with jax.profiler.TraceAnnotation("kmeans.iterate.step") as st:
+                state, stats = step(x, w, state)
+                if st.is_enabled():
+                    st.set_metadata(it=it)
+            mon.pending.append(tuple(stats))
+            if it % monitor_every == 0 or it == max_iters:
+                mon.flush()
+                healed = False
+                if guard is not None:
+                    vio = np.asarray(jax.device_get(guard(state)))
+                    counter.host_reads += 1
+                    bad_energy = bool(mon.history) and \
+                        not math.isfinite(mon.history[-1][1])
+                    if vio.any() or bad_energy:
+                        if bad_energy and not vio.any():
+                            vio = np.array([0, 1, 0, 0])   # full-heal route
+                        x, w, state = heal_fit(x, w, state, sb, n, counter,
+                                               key, vio)
+                        mon.converged = False   # healed state re-iterates
+                        healed = True
+                if ckpt is not None and not healed and ckpt.due(it):
+                    if resident:
+                        ckpt.save(it, state.c,
+                                  sb.final_assignment(state, n))
+                    else:
+                        ckpt.save(it, state.c, state.a, u=state.u,
+                                  lo=state.lo, nb=state.prev_nb)
+                    counter.host_reads += 1
+                if mon.converged:
+                    break
 
-    a = sb.final_assignment(state, n) if resident else state.a
-    c = state.c
-    if mon.history and math.isfinite(mon.history[-1][1]):
-        energy = mon.history[-1][1]
-    else:       # no iterations ran, or the last flush preceded a heal
-        counter.add_distances(x.shape[0])   # n residual distances
-        energy = float(jnp.sum(w * sqnorm(x - c[a])))
+        if resident:
+            with jax.profiler.TraceAnnotation("kmeans.iterate.final"):
+                a = sb.final_assignment(state, n)
+        else:
+            a = state.a
+        c = state.c
+        if mon.history and math.isfinite(mon.history[-1][1]):
+            energy = mon.history[-1][1]
+        else:   # no iterations ran, or the last flush preceded a heal
+            counter.add_distances(x.shape[0])   # n residual distances
+            energy = float(jnp.sum(w * sqnorm(x - c[a])))
+            counter.host_reads += 1
+        if span.is_enabled():
+            span.set_metadata(iterations=mon.it_done,
+                              converged=mon.converged,
+                              rows_recomputed=mon.rows_recomputed)
     return KMeansResult(c, a, energy, mon.it_done, counter.total,
                         mon.history)
 

@@ -4,7 +4,7 @@ The paper (§3) measures runtime complexity as the number of *vector operations*
 (distances, inner products, additions — all O(d)), counting sorts as
 ``|X_j| * log2(|X_j|) / d`` vector-op equivalents so that comparisons are
 charged fairly. We reproduce that accounting exactly so that the speedup
-tables are machine-independent, and additionally log wall-clock for reference.
+tables are machine-independent.
 
 Alongside the paper's op metric the counter tracks a *memory-traffic* metric
 (bytes gathered / scattered / sorted by layout maintenance, DESIGN.md §9):
@@ -29,7 +29,6 @@ from __future__ import annotations
 
 import dataclasses
 import math
-import time
 
 
 @dataclasses.dataclass
@@ -70,7 +69,11 @@ class OpCounter:
     degrades: dict = dataclasses.field(
         default_factory=lambda: {"int8_scan": 0, "probe_shrink": 0,
                                  "route_only": 0, "shed": 0})
-    wall_t0: float = dataclasses.field(default_factory=time.perf_counter)
+    # blocking device-to-host reads of the fit path: the input check, each
+    # GDI round (or host-loop split), each monitor flush, guard, fallback
+    # energy and checkpoint read; ``api.fit``'s ``kmeans.fit`` span
+    # carries one fit's count
+    host_reads: int = 0
 
     @property
     def total(self) -> float:
@@ -81,10 +84,6 @@ class OpCounter:
     def bytes_moved(self) -> float:
         """Total layout memory traffic (gather + scatter + sort bytes)."""
         return self.bytes_gathered + self.bytes_scattered + self.bytes_sorted
-
-    @property
-    def wall(self) -> float:
-        return time.perf_counter() - self.wall_t0
 
     @staticmethod
     def _integral(n, kind: str) -> float:
@@ -192,7 +191,7 @@ class OpCounter:
             "retries": self.retries,
             "sanitized_rows": self.sanitized_rows,
             "evicted_rows": self.evicted_rows,
-            "wall_s": self.wall,
+            "host_reads": self.host_reads,
         }
 
 
