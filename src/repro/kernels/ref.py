@@ -69,9 +69,7 @@ def segmented_scan_ref(x, w, block2seg, bn: int, num_segments: int):
     Same contract as ``segmented_scan``: rows grouped by segment (block
     aligned, ``block2seg`` non-decreasing), ``w`` zero on padding rows.
     Realised as a global inclusive cumsum minus the per-segment exclusive
-    offset (``segment_sum`` totals, exclusive-scanned over segments) — the
-    device-resident formulation the XLA fast path of the divisive init
-    uses directly.
+    offset (``segment_sum`` totals, exclusive-scanned over segments).
     """
     row_seg = jnp.repeat(block2seg, bn)
     xw = x * w[:, None]

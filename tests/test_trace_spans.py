@@ -97,7 +97,19 @@ def test_one_round_span_per_gdi_round(traced):
     leaves = [s[3]["leaves"] for s in rounds]
     assert all(a < b for a, b in zip(leaves, leaves[1:]))
     assert leaves[-1] == K
-    assert init[3] == {"rounds": len(rounds), "leaves": K}
+    assert init[3] == {"rounds": len(rounds), "leaves": K,
+                       "rows_swept": init[3]["rows_swept"],
+                       "rows_full": init[3]["rows_full"]}
+
+
+def test_init_span_counts_the_rows_its_rounds_swept(traced):
+    _, _, spans = traced
+    init, = _named(spans, "kmeans.init")
+    rows = [s[3]["rows"] for s in _named(spans, "kmeans.init.round")]
+    assert init[3]["rows_swept"] == sum(rows)
+    assert 0 < init[3]["rows_swept"] <= init[3]["rows_full"]
+    full, rest = divmod(init[3]["rows_full"], len(rows))
+    assert rest == 0 and max(rows) <= full
 
 
 def test_one_flush_per_iteration(traced):
