@@ -2,10 +2,15 @@
 
 A unit is one ``repro.core.api.fit`` call with the configuration's fit
 settings, from the initialization through its iterations, waited for.
-The configuration fixes the rows (``mixture_seed``, ``rows_seed``); the
-run's seed draws only the fit's key, so every run does the same work.
-Set-up makes the rows on the device and runs one fit to warm every
-program.
+The configuration fixes the rows (``mixture_seed``, ``rows_seed``) and a
+panel of fit keys (``fit_seeds``): unit ``i`` fits with the key of
+``fit_seeds[i % len(fit_seeds)]``, and the session's ``pass_units``, the
+panel's size, makes the harness's window cover whole passes of it. Which
+leaves GDI sweeps, and so a fit's time, depends on its key; with the
+panel fixed every run does the same work. The run's seed draws only the
+control's Forgy start. Set-up makes the rows on the device and runs one
+fit to warm every program. Each unit's key, seconds and energy ratio
+are logged after the window.
 
 Each fit's (centers, assignment) is held to the plain references of
 ``bench/reference.py``:
@@ -19,10 +24,13 @@ Each fit's (centers, assignment) is held to the plain references of
   partition, less 1 (the initialization: on this mixture a start that
   leaves small components bare ends in a minimum far above GDI's).
 
-The end-to-end ``fit_energy_ratio`` is the fit's energy over that of
-plain HIGHEST Lloyd from the configuration's fixed Forgy start.
+The end-to-end ``fit_energy_ratio`` is the mean over the window's fits
+of the fit's energy over that of plain HIGHEST Lloyd from the
+configuration's fixed Forgy start.
 """
 from __future__ import annotations
+
+import time
 
 import jax
 import jax.numpy as jnp
@@ -42,22 +50,42 @@ def make_rows(cell):
     return jax.block_until_ready((x, comp))
 
 
+def fit_key(seed: int) -> jax.Array:
+    """The fit key that ``seed`` gives: the first half of its split (the
+    second is the control's)."""
+    return jax.random.split(ref.seed_key(seed))[0]
+
+
 class FitSession:
     span = "fit"
 
-    def __init__(self, cell, seed: int):
+    def __init__(self, cell, seed: int, fit_seeds=None):
+        """``fit_seeds`` (default: the configuration's panel) replaces
+        the panel of fit keys; only ``bench/readings.py`` sets it."""
         from repro.core import api
         self.cell = cell
         self.fit_kw = dict(cell.config["fit"])
         self.k = self.fit_kw.pop("k")
         self.iters = self.fit_kw["max_iters"]
         self.x, self.comp = make_rows(cell)
-        self.k_fit, self.k_control = jax.random.split(ref.seed_key(seed))
+        self.fit_seeds = list(cell.config["fit_seeds"] if fit_seeds is None
+                              else fit_seeds)
+        self.pass_units = len(self.fit_seeds)
+        self.fit_keys = [fit_key(s) for s in self.fit_seeds]
+        self.k_control = jax.random.split(ref.seed_key(seed))[1]
+        self.seconds = []               # of units 0, 1, ...
         self._fit = api.fit
 
     def unit(self, i: int):
-        res = self._fit(self.x, self.k, key=self.k_fit, **self.fit_kw)
-        return jax.block_until_ready((res.centers, res.assignment))
+        """Unit ``i`` (-1: set-up's warm-up) fits with the panel's key
+        ``i`` modulo its size."""
+        t0 = time.perf_counter()
+        res = self._fit(self.x, self.k, key=self.fit_keys[i % self.pass_units],
+                        **self.fit_kw)
+        out = jax.block_until_ready((res.centers, res.assignment))
+        if i >= 0:
+            self.seconds.append(time.perf_counter() - t0)
+        return out
 
     def release(self) -> None:
         self._fit = None
@@ -88,6 +116,10 @@ class FitSession:
         self.ratios = [v / e_yard for v in e]
         log(f"energies: yardstick {e_yard!r}, mixture partition "
             f"{e_truth!r}, fits {e!r}")
+        for i, r in enumerate(self.ratios):
+            secs = self.seconds[i] if i < len(self.seconds) else None
+            log(f"unit {i}: fit key {self.fit_seeds[i % self.pass_units]}, "
+                f"{secs!r} s, energy ratio {r!r}")
         return {"center_gap": max(gap), "lloyd_gain": max(gain),
                 "truth_excess": max(e) / e_truth - 1.0}
 
@@ -97,8 +129,9 @@ class FitSession:
                 "fit_energy_ratio": sum(self.ratios) / len(self.ratios)}
 
 
-def setup(cell, seed: int, warm: bool = True) -> FitSession:
-    session = FitSession(cell, seed)
+def setup(cell, seed: int, warm: bool = True,
+          fit_seeds=None) -> FitSession:
+    session = FitSession(cell, seed, fit_seeds)
     if warm:
         session.unit(-1)                  # every program compiled
     return session

@@ -12,11 +12,13 @@ here changes.
 
 A run: the driver's ``setup`` (data made on the device, the program
 built and warmed on every shape the window uses) is ``setup_s``. Then
-either the measured window, a closed loop of the driver's units in which
-every unit that starts inside ``seconds`` counts and the last finishes,
-or, with ``trace``, the traffic's ``trace_units`` units under the
-profiler. Then the device's peak memory is read, the program's state is
-freed, and the units' outputs are compared with the plain reference.
+either the measured window, a closed loop of the driver's units that
+covers whole passes of the session's ``pass_units`` units (every unit
+that starts inside ``seconds`` counts, the last finishes, and units go
+on until the count is a whole number of passes), or, with ``trace``,
+the traffic's ``trace_units`` units under the profiler. Then the
+device's peak memory is read, the program's state is freed, and the
+units' outputs are compared with the plain reference.
 """
 from __future__ import annotations
 
@@ -146,15 +148,19 @@ def memory_peak(devices) -> int:
 
 
 def window(session, seconds: float, counter: CompileCounter):
-    """The closed loop: units back to back; every unit that starts
-    before ``seconds`` have passed counts and runs to its end. Returns
-    (outputs, elapsed seconds from the first start to the last end,
-    units failed)."""
+    """The closed loop: units back to back in whole passes of the
+    session's ``pass_units`` (1 where it declares none). Every unit that
+    starts before ``seconds`` have passed counts and runs to its end, and
+    units go on starting until their count is a whole number of passes,
+    at least one. Returns (outputs, elapsed seconds from the first start
+    to the last end, units failed)."""
+    per_pass = getattr(session, "pass_units", 1)
     outputs, failed = [], 0
     counter.active = True
     t0 = time.perf_counter()
     try:
-        while time.perf_counter() - t0 < seconds:
+        while not outputs or len(outputs) % per_pass or \
+                time.perf_counter() - t0 < seconds:
             try:
                 outputs.append(session.unit(len(outputs)))
             except Exception as e:          # a failed unit ends the window
@@ -167,10 +173,24 @@ def window(session, seconds: float, counter: CompileCounter):
     return outputs, elapsed, failed
 
 
+def reduce_trace(path: str, span: str):
+    """The xplane file ``path``, parsed once, clipped to the benchmark's
+    spans named ``span``: (the device summary of ``trace_reduce``, the
+    program span table of ``span_reduce``)."""
+    from bench import span_reduce, trace_reduce
+    pd = trace_reduce.profile_data(path)
+    tr = trace_reduce.load(pd, [span])
+    groups = trace_reduce.load_groups(os.path.join(BENCH, "program_groups"))
+    summary = trace_reduce.reduce(tr, [span], groups)
+    table = span_reduce.reduce_spans(
+        tr, span_reduce.load_spans(pd, [span]), [span])
+    return summary, table
+
+
 def traced(session, cell: Cell, counter: CompileCounter, trace_dir: str):
     """The traffic's ``trace_units`` units, each in a span named by the
-    driver, under the profiler. Returns (outputs, trace summary,
-    units failed)."""
+    driver, under the profiler. Returns (outputs, trace summary, program
+    span table, units failed)."""
     import jax
     from bench import trace_reduce
     shutil.rmtree(trace_dir, ignore_errors=True)
@@ -193,12 +213,12 @@ def traced(session, cell: Cell, counter: CompileCounter, trace_dir: str):
         counter.active = False
     t0 = time.perf_counter()
     path = trace_reduce.find_xplane(trace_dir)
-    groups = trace_reduce.load_groups(os.path.join(BENCH, "program_groups"))
-    tr = trace_reduce.load(path, [session.span])
-    summary = trace_reduce.reduce(tr, [session.span], groups)
-    log(f"trace {os.path.getsize(path)} bytes reduced in "
-        f"{time.perf_counter() - t0:.1f} s")
-    return outputs, summary, failed
+    summary, table = reduce_trace(path, session.span)
+    log(f"trace of {len(summary.spans)} unit(s), {os.path.getsize(path)} "
+        f"bytes, reduced in {time.perf_counter() - t0:.1f} s")
+    for line in table.lines():
+        log(line)
+    return outputs, summary, table, failed
 
 
 def judge(numbers: dict, limits: dict) -> dict:
@@ -231,10 +251,11 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
     session = driver.setup(cell, seed)
     setup_s = time.perf_counter() - t_start
     log(f"set-up {setup_s:.3f} s")
-    summary = None
+    summary = table = None
     if trace:
         trace_dir = os.path.join(ROOT, ".bench_trace", cell.name)
-        outputs, summary, failed = traced(session, cell, counter, trace_dir)
+        outputs, summary, table, failed = traced(session, cell, counter,
+                                                 trace_dir)
         elapsed = None
     else:
         outputs, elapsed, failed = window(session, seconds, counter)
@@ -254,7 +275,9 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
     metrics = {}
     units = {m["name"]: m["unit"] for m in cell.end_to_end + cell.per_layer}
     if trace:
-        ctx = {"summary": summary, "cell": cell, "session": session,
+        from bench import span_reduce
+        ctx = {"summary": summary, span_reduce.CONTEXT_KEY: table,
+               "cell": cell, "session": session,
                "device_kind": devices[0].device_kind}
         for m in cell.per_layer:
             reader = load_module(os.path.join(BENCH, "metrics",

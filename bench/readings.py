@@ -4,7 +4,11 @@
 
 For each seed, in one process: the cell's data and program from that
 seed, one unit of its traffic (no window), and the numbers that decide
-``correct``, judged against the cell's limits. For each control seed,
+``correct``, judged against the cell's limits. The seed also sets the
+unit's fit key, in place of the configuration's panel of ``fit_seeds``
+(the driver's ``fit_seeds`` override, which only this script sets), so
+that the limits can be read over as many keys as seeds are given, where
+a run fits the panel's few. For each control seed,
 the same numbers for the control: the plain reference put in the
 program's place one precision down (the driver's ``control``), which
 has to fail a limit. ``--fault`` plants one of ``faults.py``'s faults
@@ -52,7 +56,7 @@ def main(argv=None) -> int:
     seen: dict[str, dict[str, list[float]]] = {}
     for seed in args.seeds:
         t0 = time.perf_counter()
-        session = driver.setup(cell, seed, warm=False)
+        session = driver.setup(cell, seed, warm=False, fit_seeds=[seed])
         out = session.unit(0)
         ctl = session.control() if seed in args.control_seeds else None
         session.release()

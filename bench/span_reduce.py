@@ -14,15 +14,14 @@ the first device's longest idle gaps by the span that holds most of
 each.
 
 The benchmark's metric readers share one :class:`SpanTable` per run
-through :func:`of`, which reads the run's trace once, keeps the table in
-the metric context under ``"program_spans"`` and logs it.
+through :func:`of`: the harness reduces it from the same parse of the
+trace as its device numbers, logs it and keeps it in the metric context
+under ``"program_spans"``.
 """
 from __future__ import annotations
 
 import bisect
 import dataclasses
-import gzip
-import os
 
 from bench import trace_reduce
 
@@ -86,16 +85,11 @@ def _layer_of(name: str) -> str | None:
     return None
 
 
-def load_spans(path: str, bench_names) -> list[Span]:
+def load_spans(pd, bench_names) -> list[Span]:
     """The program spans, with their statistics, of the host thread that
     holds any of the benchmark's spans ``bench_names`` (the thread that
-    ``trace_reduce.load`` keeps)."""
-    from jax.profiler import ProfileData
-    if path.endswith(".gz"):
-        with gzip.open(path, "rb") as f:
-            pd = ProfileData.from_serialized_xspace(f.read())
-    else:
-        pd = ProfileData.from_file(path)
+    ``trace_reduce.load`` keeps), from the ``ProfileData`` that
+    ``trace_reduce.profile_data`` parsed."""
     bench_names = set(bench_names)
     out = []
     for plane in pd.planes:
@@ -210,22 +204,15 @@ def reduce_spans(trace: trace_reduce.Trace, spans: list[Span],
 
 
 def table_of(path: str, bench_names) -> SpanTable:
-    """:func:`reduce_spans` of the trace file ``path``."""
-    trace = trace_reduce.load(path, bench_names)
-    return reduce_spans(trace, load_spans(path, bench_names), bench_names)
+    """:func:`reduce_spans` of the xplane file ``path``, parsed once."""
+    pd = trace_reduce.profile_data(path)
+    trace = trace_reduce.load(pd, bench_names)
+    return reduce_spans(trace, load_spans(pd, bench_names), bench_names)
 
 
 def of(ctx: dict) -> SpanTable | None:
-    """The run's span table, kept in ``ctx`` for the other readers: the
-    traced run's trace (``.bench_trace/<cell>`` under the checkout) read
-    once and logged. None where the trace holds no program span."""
-    if CONTEXT_KEY not in ctx:
-        from bench import harness
-        path = trace_reduce.find_xplane(
-            os.path.join(harness.ROOT, ".bench_trace", ctx["cell"].name))
-        table = table_of(path, [ctx["session"].span])
-        for line in table.lines():
-            harness.log(line)
-        ctx[CONTEXT_KEY] = table
-    table = ctx[CONTEXT_KEY]
-    return table if table.spans else None
+    """The run's span table, which the harness's traced run reduced from
+    the trace it read once and put in the metric context under
+    ``"program_spans"``. None where the trace holds no program span."""
+    table = ctx.get(CONTEXT_KEY)
+    return table if table is not None and table.spans else None

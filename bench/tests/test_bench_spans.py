@@ -122,3 +122,43 @@ def test_recorded_tpu_fit_trace():
     flushes = table.rows["kmeans.iterate.flush"].count
     assert _read("host_reads.fit", table) == 1 + rounds + flushes
     assert 0 < _read("recompute_share.fit", table) <= 100
+
+
+def test_every_reader_reads_alike_from_the_harness_single_parse():
+    """The harness parses the trace once for the device summary and the
+    span table; each reader gives what it gives from separate reads."""
+    path = os.path.join(DATA, "tiny_fit.xplane.pb.gz")
+    summary, table = harness.reduce_trace(path, "fit")
+    once = {"summary": summary, sr.CONTEXT_KEY: table}
+    groups = tr.load_groups(os.path.join(harness.BENCH, "program_groups"))
+    apart = {"summary": tr.reduce(tr.load(path, ["fit"]), ["fit"], groups),
+             sr.CONTEXT_KEY: sr.table_of(path, ["fit"])}
+    names = sorted(f[:-3] for f in os.listdir(METRICS) if f.endswith(".py"))
+    assert set(READERS) < set(names)
+    for name in names:
+        reader = harness.load_module(os.path.join(METRICS, name + ".py"))
+        assert reader.read(once) == reader.read(apart), name
+    for name in READERS:
+        assert _read(name, table) is not None
+
+
+def test_readers_give_the_mean_over_a_pass_of_three_fits():
+    """A traced pass of three fits alike reads what one fit reads: the
+    per-fit readers divide by the number of ``fit`` spans."""
+    one, spans = _hand_built()
+
+    def shift(t, j):                # fit j of the pass, 2000 ns apart
+        return t + 2000 * j
+    ops = {dev: [tr.Op(o.name, o.module, shift(o.start, j), shift(o.end, j))
+                 for j in range(3) for o in lst]
+           for dev, lst in one.ops.items()}
+    spans3 = [sr.Span(s.name, shift(s.start, j), shift(s.end, j), s.stats)
+              for j in range(3) for s in spans]
+    host = sorted([(n, shift(s, j), shift(e, j)) for j in range(3)
+                   for n, s, e in one.host], key=lambda h: h[1])
+    three = tr.Trace(ops, host)
+    t1 = sr.reduce_spans(one, spans, ["fit"])
+    t3 = sr.reduce_spans(three, spans3, ["fit"])
+    assert t3.windows == 3
+    for name in READERS:
+        assert _read(name, t3) == pytest.approx(_read(name, t1)), name

@@ -97,17 +97,22 @@ def op_name(hlo: str) -> str:
     return f"{lhs.lstrip('%')} {typ}"
 
 
-def load(path: str, span_names) -> Trace:
-    """Read one xplane file (gzipped where the name ends in ``.gz``).
-    Device planes are those named ``/device:`` with an ``XLA Ops`` line;
-    the host events kept are those of the thread that holds any of
-    ``span_names``."""
+def profile_data(path: str):
+    """The parsed ``jax.profiler.ProfileData`` of one xplane file
+    (gzipped where the name ends in ``.gz``)."""
     from jax.profiler import ProfileData
     if path.endswith(".gz"):
         with gzip.open(path, "rb") as f:
-            pd = ProfileData.from_serialized_xspace(f.read())
-    else:
-        pd = ProfileData.from_file(path)
+            return ProfileData.from_serialized_xspace(f.read())
+    return ProfileData.from_file(path)
+
+
+def load(source, span_names) -> Trace:
+    """Read one xplane file: its path, or the ``ProfileData`` that
+    :func:`profile_data` parsed from it. Device planes are those named
+    ``/device:`` with an ``XLA Ops`` line; the host events kept are those
+    of the thread that holds any of ``span_names``."""
+    pd = profile_data(source) if isinstance(source, str) else source
     ops: dict[str, list[Op]] = {}
     host: list[tuple[str, int, int]] = []
     span_names = set(span_names)
